@@ -431,19 +431,15 @@ TP_MAX_LEN = 256
 TP_LONG_LEN = 160
 
 
-def _ensure_devices(n: int) -> None:
-    """Re-exec under host-platform device emulation when the backend
-    exposes fewer than `n` devices (CI and laptops run the sharded smoke
-    on emulated CPU devices; a real mesh passes through untouched)."""
-    import jax
-
-    if jax.device_count() >= n or os.environ.get("_BENCH_TP_REEXEC"):
-        return
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={n} "
-        + os.environ.get("XLA_FLAGS", ""))
-    os.environ["_BENCH_TP_REEXEC"] = "1"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
+def _emulate_tp_devices(argv: list[str]) -> None:
+    """`--tp N` needs N devices. Decided from the arguments before JAX is
+    imported: the flag gives the CPU backend N emulated devices (CI and
+    laptops) and leaves an accelerator's devices as they are."""
+    if "--tp" in argv:
+        n = int(argv[argv.index("--tp") + 1])
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={n} "
+            + os.environ.get("XLA_FLAGS", ""))
 
 
 def _build_tp():
@@ -1113,7 +1109,6 @@ def main(argv: list[str]) -> int:
               f"{CHAOS_JTOK_RATIO_MAX}, BASELINE downgrade flagged")
     if "--tp" in argv:
         tp = int(argv[argv.index("--tp") + 1])
-        _ensure_devices(tp)
         special = True
         tp_rows, tp_payload = run_tp(tp, smoke)
         for r in tp_rows:
@@ -1222,4 +1217,5 @@ def main(argv: list[str]) -> int:
 
 
 if __name__ == "__main__":
+    _emulate_tp_devices(sys.argv[1:])
     sys.exit(main(sys.argv[1:]))

@@ -14,12 +14,14 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.config import ModelConfig
 from repro.models.registry import get_model
 from repro.serving.engine import Request, ServingEngine
 
 
 def main():
+    enable_compile_cache()
     cfg = ModelConfig(
         name="serve-demo", kind="dense", n_layers=4, d_model=256, n_heads=8,
         n_kv_heads=4, d_ff=1024, vocab=4096, param_dtype="float32",

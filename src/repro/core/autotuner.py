@@ -131,6 +131,10 @@ class GemmAutotuner:
         ] = OrderedDict()
         self._cand_cache_size = candidate_cache_size
         self._lock = threading.Lock()
+        # (m, n, k, dtype) shapes no candidate block fits: tuned to
+        # BASELINE instead of a predictor pick (serving checks this stays
+        # empty)
+        self.fallback_shapes: set[tuple[int, int, int, str]] = set()
         if cache_path and os.path.exists(cache_path):
             self._cache = self._load_cache_file(cache_path)
 
@@ -484,6 +488,7 @@ class GemmAutotuner:
                     groups.append((i, top))
                 else:
                     out[i] = BASELINE
+                    self.fallback_shapes.add((*shapes[i], dtype))
         else:
             trace_groups: list[tuple[int, list[GemmConfig], np.ndarray]] = []
             for i in todo:
@@ -491,6 +496,7 @@ class GemmAutotuner:
                 cfgs, X = self.candidate_table(m, n, k, dtype)
                 if not cfgs:
                     out[i] = BASELINE
+                    self.fallback_shapes.add((m, n, k, dtype))
                 else:
                     trace_groups.append((i, cfgs, X))
             if trace_groups:
@@ -577,9 +583,11 @@ _GLOBAL_LOCK = threading.Lock()
 def build_default_predictor(artifacts_dir: str = DEFAULT_ARTIFACTS_DIR,
                             n_train: int = 4000,
                             force_retrain: bool = False,
-                            chip: ChipSpec | str = TPU_V5E) -> PerfPredictor:
+                            chip: ChipSpec | str = TPU_V5E,
+                            seed: int = 0) -> PerfPredictor:
     """Load the persisted per-chip predictor artifact or train one on a
-    fresh sweep. Invalid/legacy/tampered artifacts trigger a retrain."""
+    fresh sweep drawn from `seed`. Invalid/legacy/tampered artifacts
+    trigger a retrain."""
     chip = get_chip(chip)
     os.makedirs(artifacts_dir, exist_ok=True)
     path = os.path.join(artifacts_dir, f"perf_predictor_{chip.name}.npz")
@@ -590,7 +598,7 @@ def build_default_predictor(artifacts_dir: str = DEFAULT_ARTIFACTS_DIR,
             pass
     from repro.core.profiler import collect_dataset
 
-    table = collect_dataset(n_configs=n_train, seed=0, chip=chip)
+    table = collect_dataset(n_configs=n_train, seed=seed, chip=chip)
     pred = PerfPredictor(model="rf", residual=True, fast=True,
                          chip=chip.name).fit(table)
     pred.save(path)

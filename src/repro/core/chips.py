@@ -133,6 +133,21 @@ def available_chips() -> list[str]:
 register_chip(TPU_V5E, "v5e")
 register_chip(RTX_4070, "rtx_4070", "ada", "4070")
 
+# `jax.Device.device_kind` strings -> registry names (a v5e reports
+# "TPU v5 lite")
+DEVICE_KINDS: dict[str, str] = {"TPU v5 lite": "tpu_v5e", "TPU v5e": "tpu_v5e"}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """Resolve the chip spec of an attached device from its
+    `device_kind`; a kind without an entry raises (no default chip)."""
+    try:
+        return get_chip(DEVICE_KINDS[kind])
+    except KeyError:
+        raise ValueError(
+            f"no chip spec for device kind {kind!r}; known kinds: "
+            f"{sorted(DEVICE_KINDS)}") from None
+
 
 # Trace-time dtype strings (str(jnp_array.dtype)) -> simulator dtype names.
 # The substrate's peak-FLOPs tables are keyed by the short names only, so

@@ -23,7 +23,7 @@ Two precisions, same contract as the forest predictor always had:
     programs; thresholds are nudged one fp32 ulp (see
     `tree.cast_flat_ensemble`) so fp64-trained splits survive rounding.
   * ``float64=True`` — arrays stay float64 (build and call under a scoped
-    ``jax.experimental.enable_x64``); every gather, comparison, and
+    ``jax.enable_x64``); every gather, comparison, and
     accumulation happens in the same order as the numpy reference, so the
     compiled scorer is bit-identical to `est.predict`.
 
@@ -86,9 +86,9 @@ def precision_scope(x64: bool):
     """Scoped x64 so float64 arrays survive asarray/tracing; the default
     fp32 path is a no-op context."""
     if x64:
-        from jax.experimental import enable_x64
+        import jax
 
-        return enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 

@@ -17,7 +17,7 @@ Two precisions:
     Tree thresholds are nudged one ulp so most fp64-trained splits survive
     fp32 rounding, but near-threshold samples can still flip branches.
   * ``x64=True`` — arrays stay float64 (built and called under a scoped
-    ``jax.experimental.enable_x64``), and every accumulation runs in the
+    ``jax.enable_x64``), and every accumulation runs in the
     numpy reference's order, so predictions are bit-identical to
     `est.predict`. This is what the autotuner's serving scorer uses: XLA
     speed with exact-parity predictions.

@@ -53,7 +53,7 @@ def compressed_allreduce_tree(grads, key: jax.Array, mesh, axis: str = "pod",
     """Apply compressed_psum leaf-wise over `axis` via shard_map. Grads must
     already be reduced over other axes. Residual tree is threaded through."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if axis not in mesh.axis_names:
         return grads, residuals
@@ -68,7 +68,7 @@ def compressed_allreduce_tree(grads, key: jax.Array, mesh, axis: str = "pod",
             shard_map, mesh=mesh,
             in_specs=(P(), P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         def _one(x, r, kk):
             return compressed_psum(x, kk, axis, residual=r, bits=bits)

@@ -26,8 +26,11 @@ on the wire while chunk c+1's GEMM occupies the MXU — the double-buffered
 SUMMA-pipelining idea, with identical numerics (per-chunk reductions touch
 disjoint output columns).
 
-Per-shard dots keep fp32 accumulation (preferred_element_type) — only the
-wire format changes. Weight grads stay sharded like the weights; the data-
+Forward per-shard GEMMs run through `kernels.ops.matmul` inside the
+shard_map — the predictor-tuned Pallas kernel on TPU, on each chip's own
+(M, N/tp, K) shard (a Mosaic kernel cannot be partitioned by GSPMD, so it
+only ever runs on shards). They keep fp32 accumulation — only the wire
+format changes. Weight grads stay sharded like the weights; the data-
 axis gradient reduction stays with SPMD (bf16 cotangents).
 """
 
@@ -37,7 +40,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import current_mesh, dp_axes
@@ -48,14 +51,25 @@ def _dp(mesh):
     return ax if len(ax) > 1 else (ax[0] if ax else None)
 
 
-def _dot(x, w):
-    return jax.lax.dot_general(
-        x, w, (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+def _dot(x, w, out_dtype=None):
+    """One shard's GEMM, fp32-accumulated, cast to `out_dtype` (default:
+    x's dtype) before anything goes on the wire."""
+    from repro.kernels import ops
+
+    return ops.matmul(x, w, out_dtype=out_dtype)
+
+
+def _replicated_dot(x, w, mesh, out_dtype=None):
+    """`_dot` on fully replicated operands, one kernel per chip: the
+    fallback for projections whose extents the mesh does not divide
+    (the contraction stays unsharded, so numerics match tp=1)."""
+    return shard_map(functools.partial(_dot, out_dtype=out_dtype),
+                     mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+                     check_vma=False)(x, w)
 
 
 def _ctx(ctx):
-    """Unpack a (mesh,) or (mesh, chunks) nondiff context tuple."""
+    """Unpack a row-parallel (mesh,) or (mesh, chunks) context tuple."""
     mesh = ctx[0]
     chunks = int(ctx[1]) if len(ctx) > 1 else 1
     return mesh, max(chunks, 1)
@@ -72,30 +86,32 @@ def _n_chunks(n_cols: int, chunks: int) -> int:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def column_parallel(x: jax.Array, w: jax.Array, ctx: tuple) -> jax.Array:
     """x: (..., d) replicated over model; w: (d, F) col-sharded on F.
-    Returns y: (..., F) col-sharded."""
+    Returns y: (..., F) col-sharded. `ctx` is (mesh,) or (mesh,
+    out_dtype) — the LM head keeps fp32 logits."""
     return _col_fwd(x, w, ctx)[0]
 
 
 def _col_fwd(x, w, ctx):
-    mesh, = ctx
+    mesh = ctx[0]
     dp = _dp(mesh)
 
     def local(xl, wl):
-        return _dot(xl, wl).astype(xl.dtype)
+        return _dot(xl, wl, ctx[1] if len(ctx) > 1 else None)
 
     y = shard_map(local, mesh=mesh,
                   in_specs=(P(dp), P(None, "model")),
                   out_specs=P(dp, *([None] * (x.ndim - 2)), "model"),
-                  check_rep=False)(x, w)
+                  check_vma=False)(x, w)
     return y, (x, w)
 
 
 def _col_bwd(ctx, res, g):
-    mesh, = ctx
+    mesh = ctx[0]
     x, w = res
     dp = _dp(mesh)
     dp_names = dp if isinstance(dp, tuple) else ((dp,) if dp else ())
     lead = x.ndim - 1
+    g = g.astype(x.dtype)
 
     def local(gl, wl, xl):
         # dx: partial over the model axis; cast BEFORE the psum -> bf16 wire
@@ -118,7 +134,7 @@ def _col_bwd(ctx, res, g):
         in_specs=(P(dp, *([None] * (lead - 1)), "model"),
                   P(None, "model"), P(dp)),
         out_specs=(P(dp), P(None, "model")),
-        check_rep=False)(g, w, x)
+        check_vma=False)(g, w, x)
     return dx, dw
 
 
@@ -139,14 +155,14 @@ def _row_fwd(x, w, ctx):
 
     def local(xl, wl):
         if c == 1:
-            yl = _dot(xl, wl).astype(xl.dtype)   # cast before the wire
+            yl = _dot(xl, wl)   # cast before the wire
             return jax.lax.psum(yl, "model")
         # interleaved chunks: psum(chunk i) rides the wire while the MXU
         # computes chunk i+1 (disjoint columns -> identical numerics)
         width = wl.shape[-1] // c
         outs = [jax.lax.psum(
             _dot(xl, jax.lax.slice_in_dim(wl, i * width, (i + 1) * width,
-                                          axis=1)).astype(xl.dtype),
+                                          axis=1)),
             "model") for i in range(c)]
         return jnp.concatenate(outs, axis=-1)
 
@@ -154,7 +170,7 @@ def _row_fwd(x, w, ctx):
                   in_specs=(P(dp, *([None] * (x.ndim - 2)), "model"),
                             P("model", None)),
                   out_specs=P(dp),
-                  check_rep=False)(x, w)
+                  check_vma=False)(x, w)
     return y, (x, w)
 
 
@@ -184,7 +200,7 @@ def _row_bwd(ctx, res, g):
                   P(dp, *([None] * (x.ndim - 2)), "model")),
         out_specs=(P(dp, *([None] * (x.ndim - 2)), "model"),
                    P("model", None)),
-        check_rep=False)(g, w, x)
+        check_vma=False)(g, w, x)
     return dx, dw
 
 
@@ -215,7 +231,7 @@ def _row_gather_fwd(x, w, ctx):
         outs = []
         for i in range(c):
             yl = _dot(xf, jax.lax.slice_in_dim(
-                wl, i * width, (i + 1) * width, axis=1)).astype(xl.dtype)
+                wl, i * width, (i + 1) * width, axis=1))
             # gather of chunk i overlaps chunk i+1's GEMM in the schedule
             outs.append(jax.lax.all_gather(yl, "model", axis=yl.ndim - 1,
                                            tiled=True))
@@ -233,7 +249,7 @@ def _row_gather_fwd(x, w, ctx):
                   in_specs=(P(dp, *([None] * (x.ndim - 2)), "model"),
                             P(None, "model")),
                   out_specs=P(dp),
-                  check_rep=False)(x, w)
+                  check_vma=False)(x, w)
     return y, (x, w)
 
 
@@ -273,7 +289,7 @@ def _row_gather_bwd(ctx, res, g):
                   P(dp, *([None] * (x.ndim - 2)), "model")),
         out_specs=(P(dp, *([None] * (x.ndim - 2)), "model"),
                    P(None, "model")),
-        check_rep=False)(g, w, x)
+        check_vma=False)(g, w, x)
     return dx, dw
 
 
@@ -311,13 +327,18 @@ def replicate_for_parity(x: jax.Array, cfg) -> jax.Array:
     return x
 
 
-def tp_column(x, w, cfg):
-    if tp_enabled(cfg) and w.shape[-1] % current_mesh().shape["model"] == 0:
-        return column_parallel(x, w, (current_mesh(),))
-    from repro.kernels import ops
-    if tp_enabled(cfg):
-        x = replicate(x)
-    return ops.matmul(x, w)
+def tp_column(x, w, cfg, out_dtype=None):
+    """x @ w, column-parallel under explicit TP (output cast to
+    `out_dtype`, default x's dtype)."""
+    if not tp_enabled(cfg):
+        from repro.kernels import ops
+
+        return ops.matmul(x, w, out_dtype=out_dtype)
+    mesh = current_mesh()
+    if w.shape[-1] % mesh.shape["model"] == 0:
+        ctx = (mesh,) if out_dtype is None else (mesh, jnp.dtype(out_dtype))
+        return column_parallel(x, w, ctx)
+    return _replicated_dot(x, w, mesh, out_dtype)
 
 
 def tp_row(x, w, cfg):
@@ -331,6 +352,6 @@ def tp_row(x, w, cfg):
         if getattr(cfg, "tp_reduce", "psum") != "gather" \
                 and w.shape[0] % tp == 0:
             return row_parallel(x, w, (mesh, chunks))
-        x = replicate(x)          # keep the fallback contraction unsharded
+        return _replicated_dot(x, w, mesh)
     from repro.kernels import ops
     return ops.matmul(x, w)

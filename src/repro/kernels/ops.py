@@ -25,6 +25,8 @@ from repro.kernels.tiled_matmul import BlockConfig, DEFAULT_CONFIG, tiled_matmul
 
 _MODE: Literal["auto", "pallas", "pallas_interpret", "xla"] = "auto"
 _CHIP: str = "tpu_v5e"
+# (m, n, k, dtype) -> BlockConfig of every kernel call traced so far
+_TRACED: dict[tuple[int, int, int, str], BlockConfig] = {}
 
 
 def force_mode(mode: Literal["auto", "pallas", "pallas_interpret", "xla"]):
@@ -41,6 +43,12 @@ def force_chip(chip: str) -> None:
     _CHIP = get_chip(chip).name
 
 
+def traced_configs() -> dict[tuple[int, int, int, str], BlockConfig]:
+    """The block config of every Pallas GEMM traced in this process,
+    keyed by (m, n, k, dtype) — what the compiled programs run."""
+    return dict(_TRACED)
+
+
 def _resolve_mode() -> str:
     if _MODE != "auto":
         return _MODE
@@ -50,21 +58,20 @@ def _resolve_mode() -> str:
 @functools.lru_cache(maxsize=None)
 def _tuned_config(m: int, n: int, k: int, dtype: str,
                   objective: str, chip: str) -> BlockConfig:
+    """The predictor's block config for one GEMM call. A tuner failure
+    raises: a kernel silently run at an untuned default would hide that
+    the paper's technique never ran."""
     # Late import: autotuner depends on the trained predictor artifacts.
-    try:
-        from repro.core.autotuner import get_tuner
+    from repro.core.autotuner import get_tuner
 
-        return get_tuner(chip=chip).best_config(m, n, k, dtype=dtype,
-                                                objective=objective)
-    except Exception:
-        return DEFAULT_CONFIG
+    return get_tuner(chip=chip).best_config(m, n, k, dtype=dtype,
+                                            objective=objective)
 
 
 def warm_gemm_cache(shapes, *, dtype: str = "bfloat16",
                     objective: str = "runtime",
                     chip: str | None = None,
-                    rank_mode: str = "auto",
-                    strict: bool = False) -> dict[tuple, BlockConfig]:
+                    rank_mode: str = "auto") -> dict[tuple, BlockConfig]:
     """Pre-tune a fleet of (m, n, k) GEMM shapes in one batched
     `tune_many` pass and prime the trace-time config cache, so the first
     jit trace of a model pays zero per-shape tuning latency.
@@ -77,33 +84,18 @@ def warm_gemm_cache(shapes, *, dtype: str = "bfloat16",
     `force_chip` selects it. `rank_mode` selects the candidate-ranking
     path ("auto" ranks fully in-graph on accelerator backends — see
     `GemmAutotuner.rank_in_graph` — and at trace time on CPU; "graph" /
-    "trace" force one). Returns {shape: BlockConfig}; on any tuner
-    failure (e.g. no artifacts and no substrate) returns {} and traces
-    fall back to DEFAULT_CONFIG exactly like the untuned path.
-
-    ``strict=True`` re-raises tuner failures instead of degrading
-    silently — the serving engine's mid-run `retune` needs to *observe*
-    a corrupt predictor artifact (`core.predictor.ArtifactError`) so it
-    can flag degraded-mode tuning rather than quietly pricing on
-    defaults.
+    "trace" force one). Returns {shape: BlockConfig}. Tuner failures
+    raise (the serving engine's `retune` catches a corrupt predictor
+    artifact, `core.predictor.ArtifactError`, to flag degraded-mode
+    tuning).
     """
+    from repro.core.autotuner import get_tuner
+    from repro.core.chips import get_chip
+
     shapes = [tuple(int(x) for x in s) for s in shapes]
-    # validate eagerly: a rank_mode typo must stay loud, not vanish into
-    # the tuner-failure fallback below
-    if rank_mode not in ("auto", "graph", "trace"):
-        raise ValueError(f"unknown rank_mode {rank_mode!r}")
-    try:
-        from repro.core.autotuner import get_tuner
-        from repro.core.chips import get_chip
-
-        chip_name = get_chip(chip).name if chip else _CHIP
-
-        best = get_tuner(chip=chip_name).tune_many(
-            shapes, dtype=dtype, objective=objective, rank_mode=rank_mode)
-    except Exception:
-        if strict:
-            raise
-        return {}
+    chip_name = get_chip(chip).name if chip else _CHIP
+    best = get_tuner(chip=chip_name).tune_many(
+        shapes, dtype=dtype, objective=objective, rank_mode=rank_mode)
     for m, n, k in shapes:
         # the tuner cache is hot now, so this just fills the lru wrapper
         _tuned_config(m, n, k, dtype, objective, chip_name)
@@ -236,8 +228,7 @@ def warm_fleet_gemm_cache(specs, *, objective: str = "runtime",
     different chips each warm their own chip's tuner/winner caches.
     Returns one ``{shape: BlockConfig}`` dict per input spec (the
     engine's own shapes only), suitable for `ServingEngine.pretuned`;
-    tuner failures degrade to ``{}`` per group exactly like
-    `warm_gemm_cache`."""
+    tuner failures raise like `warm_gemm_cache`."""
     specs = [dict(sp) for sp in specs]
     fleets: list[list[tuple[int, int, int]]] = []
     groups: dict[tuple, set] = {}
@@ -288,6 +279,7 @@ def matmul(
         ).astype(out_dtype)
     else:
         cfg = config or _tuned_config(m, n, k, str(a.dtype), objective, _CHIP)
+        _TRACED[(m, n, k, str(a.dtype))] = cfg
         out = tiled_matmul(
             a.reshape(m, k), b,
             config=cfg,

@@ -11,10 +11,15 @@ feeds the MXU via `lax.dot_general`:
 
 Supports alpha/beta scaling (the paper's CUTLASS sweep axis), all four
 nn/nt/tn/tt layouts (transposes happen on the VMEM-resident block, feeding
-the MXU directly), bf16/f32 inputs with fp32 accumulation.
+the MXU directly), bf16/f32 inputs with fp32 accumulation. The C operand
+is streamed only when beta != 0.
 
 TARGET is TPU (compiled path); correctness is validated on CPU with
-`interpret=True` against `ref.matmul_ref` in tests.
+`interpret=True` against `ref.matmul_ref` in tests, and every block shape
+of the tuner's candidate grid is compiled for a described v5e in
+`tests/test_tpu_compile.py`. The kernel asks Mosaic for exactly the VMEM
+its blocks need (`BlockConfig.vmem_bytes`): the default scoped limit is
+smaller than the tuner's largest admitted blocks.
 """
 
 from __future__ import annotations
@@ -28,9 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+# Mosaic's own scratch on top of `BlockConfig.vmem_bytes`: compiling for
+# v5e, 2 MiB is too little for a 1024x512x2048 bf16 block and 4 MiB fits
+# every candidate of the tuner's grid; 8 MiB keeps a margin
+VMEM_HEADROOM_BYTES = 8 * 2**20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,11 +47,16 @@ class BlockConfig:
     block_n: int = 256
     block_k: int = 512
 
-    def vmem_bytes(self, in_bytes: int = 2, acc_bytes: int = 4,
-                   stages: int = 2) -> int:
-        return stages * (self.block_m * self.block_k
-                         + self.block_k * self.block_n) * in_bytes + (
-            self.block_m * self.block_n * acc_bytes)
+    def vmem_bytes(self, in_bytes: int = 2, out_bytes: int = 2,
+                   with_c: bool = False) -> int:
+        """VMEM the pipelined kernel holds: double-buffered A and B
+        blocks, the double-buffered output block (and C input block when
+        beta != 0), the fp32 accumulator and the fp32 dot result that is
+        added into it."""
+        bm, bn, bk = self.block_m, self.block_n, self.block_k
+        out_blocks = 2 if with_c else 1
+        return (2 * (bm * bk + bk * bn) * in_bytes
+                + 2 * out_blocks * bm * bn * out_bytes + 2 * bm * bn * 4)
 
     def as_tuple(self) -> tuple[int, int, int]:
         return (self.block_m, self.block_n, self.block_k)
@@ -54,10 +65,11 @@ class BlockConfig:
 DEFAULT_CONFIG = BlockConfig()
 
 
-def _matmul_kernel(a_ref, b_ref, c_in_ref, c_ref, acc_ref, *,
-                   alpha: float, beta: float, n_k_steps: int,
-                   transpose_a: bool, transpose_b: bool):
-    """One (i, j, k) grid step: acc += A_blk @ B_blk, flush at last k."""
+def _matmul_kernel(a_ref, b_ref, *refs, alpha: float, beta: float,
+                   n_k_steps: int, transpose_a: bool, transpose_b: bool):
+    """One (i, j, k) grid step: acc += A_blk @ B_blk, flush at last k.
+    `refs` is (c_in, out, acc) when beta != 0, else (out, acc)."""
+    c_in_ref, c_ref, acc_ref = refs if beta != 0.0 else (None, *refs)
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -132,6 +144,9 @@ def tiled_matmul(
     bm, bn, bk = config.block_m, config.block_n, config.block_k
     # clamp blocks to (padded) problem so tiny problems stay single-block
     bm = min(bm, math.ceil(m / 8) * 8)
+    if transpose_a:
+        # a transposed A block is (bk, bm): bm is its lane dimension
+        bm = math.ceil(bm / 128) * 128
     bn = min(bn, math.ceil(n / 128) * 128)
     bk = min(bk, math.ceil(k / 128) * 128)
 
@@ -151,11 +166,13 @@ def tiled_matmul(
     else:
         b_spec = pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j))
     c_spec = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
-
-    if c is None:
-        c_in = jnp.zeros((mp, np_), dtype=out_dtype)
-    else:
-        c_in = _pad_to(c.astype(out_dtype), (bm, bn))
+    in_specs, operands = [a_spec, b_spec], [a, b]
+    if beta != 0.0:
+        in_specs.append(c_spec)
+        operands.append(_pad_to(c.astype(out_dtype), (bm, bn)))
+    vmem = BlockConfig(bm, bn, bk).vmem_bytes(
+        in_bytes=jnp.dtype(a.dtype).itemsize,
+        out_bytes=jnp.dtype(out_dtype).itemsize, with_c=beta != 0.0)
 
     kernel = functools.partial(
         _matmul_kernel,
@@ -168,14 +185,15 @@ def tiled_matmul(
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[a_spec, b_spec, c_spec],
+        in_specs=in_specs,
         out_specs=c_spec,
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + VMEM_HEADROOM_BYTES,
         ),
         interpret=interpret,
         name=f"tiled_matmul_{bm}x{bn}x{bk}",
-    )(a, b, c_in)
+    )(*operands)
     return out[:m, :n]

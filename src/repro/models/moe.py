@@ -188,7 +188,7 @@ def moe_ffn_shard_map(p: Params, x: jax.Array, cfg: ModelConfig
     Backward collectives are the AD transposes of these — no SPMD-inferred
     full-buffer reductions (the baseline's dominant cost, §Perf).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.distributed.sharding import current_mesh
 
     mesh = current_mesh()
@@ -264,7 +264,7 @@ def moe_ffn_shard_map(p: Params, x: jax.Array, cfg: ModelConfig
                   P_("model", None, fa),       # experts w_down (E, f, d)
                   shared_specs if shared_args is not None else None),
         out_specs=(P_(batch_ax, None, None), P_()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, p["router"]["w"], w["w_gate"], w["w_up"], w["w_down"],
               shared_args)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import shard_activation
+from repro.distributed.tp import tp_column
 from repro.kernels import ops
 from repro.models import layers as L
 from repro.models.config import ModelConfig
@@ -111,7 +112,8 @@ def _unembed(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     if cfg.tie_embeddings:
         return ops.matmul(x, params["embed"]["table"], transpose_b=True,
                           out_dtype=jnp.float32)
-    return ops.matmul(x, params["head"]["w"], out_dtype=jnp.float32)
+    # vocab-parallel under explicit TP (the head is sharded on vocab)
+    return tp_column(x, params["head"]["w"], cfg, out_dtype=jnp.float32)
 
 
 def _scan_blocks(params: Params, x: jax.Array, cfg: ModelConfig,

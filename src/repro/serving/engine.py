@@ -609,50 +609,37 @@ class ServingEngine:
         head sized to the rows actually unembedded and MLA's cache-wide
         K/V decompression sized to batch_rows * max_len), cached per key.
         Under the paged layout the per-call page-gather traffic is charged
-        as extra HBM bytes. Returns None (once, with a warning) when the
-        energy model is unavailable."""
-        hit = self._step_energy_cache.get(key, "miss")
-        if hit != "miss":
+        as extra HBM bytes. A failure of the energy model raises."""
+        hit = self._step_energy_cache.get(key)
+        if hit is not None:
             return hit
-        try:
-            from repro.core.energy import gemm_fleet_energy
-            from repro.models.config import (collective_wire_bytes,
-                                             gemm_shape_counts)
+        from repro.core.energy import gemm_fleet_energy
+        from repro.models.config import (collective_wire_bytes,
+                                         gemm_shape_counts)
 
-            kv_rows = (batch_rows * self.max_len
-                       if batch_rows is not None else None)
-            wire_b, n_coll = collective_wire_bytes(
-                self.cfg, n_rows, self.tp, head_tokens=head_rows,
-                src_tokens=src_rows)
-            est = gemm_fleet_energy(
-                gemm_shape_counts(self.cfg, n_rows, head_tokens=head_rows,
-                                  kv_rows=kv_rows, tp=self.tp,
-                                  src_tokens=src_rows),
-                chip=self.chip or "tpu_v5e",
-                dtype=self.cfg.activation_dtype,
-                configs=self.pretuned or None,
-                extra_hbm_bytes=self._kv_gather_bytes(batch_rows or 0),
-                tp=self.tp, collective_bytes=wire_b,
-                n_collectives=n_coll,
-                overlap_chunks=getattr(self.cfg, "tp_overlap_chunks", 1),
-                name=f"{self.cfg.name}:{key}")
-        except Exception as e:
-            import warnings
-
-            warnings.warn(
-                f"serving energy model unavailable ({e!r}); "
-                f"energy telemetry for step {key!r} will read 0",
-                stacklevel=2)
-            est = None
+        kv_rows = (batch_rows * self.max_len
+                   if batch_rows is not None else None)
+        wire_b, n_coll = collective_wire_bytes(
+            self.cfg, n_rows, self.tp, head_tokens=head_rows,
+            src_tokens=src_rows)
+        est = gemm_fleet_energy(
+            gemm_shape_counts(self.cfg, n_rows, head_tokens=head_rows,
+                              kv_rows=kv_rows, tp=self.tp,
+                              src_tokens=src_rows),
+            chip=self.chip or "tpu_v5e",
+            dtype=self.cfg.activation_dtype,
+            configs=self.pretuned or None,
+            extra_hbm_bytes=self._kv_gather_bytes(batch_rows or 0),
+            tp=self.tp, collective_bytes=wire_b,
+            n_collectives=n_coll,
+            overlap_chunks=getattr(self.cfg, "tp_overlap_chunks", 1),
+            name=f"{self.cfg.name}:{key}")
         self._step_energy_cache[key] = est
         return est
 
     @staticmethod
     def _cost(est) -> tuple[float, float, object]:
-        """(energy_j, step_s, estimate) of a priced step — zeros (and a
-        None estimate) when the energy model is unavailable."""
-        if est is None:
-            return (0.0, 0.0, None)
+        """(energy_j, step_s, estimate) of a priced step."""
         return (est.energy_j, est.step_s, est)
 
     def _decode_cost(self) -> tuple[float, float, object]:
@@ -698,8 +685,7 @@ class ServingEngine:
     def decode_step_estimate(self):
         """Predicted `StepEnergyEstimate` of one lockstep decode step
         over the full slot table — the public handle the fleet
-        scheduler's marginal-cost pricing divides per slot (None when
-        the energy model is unavailable)."""
+        scheduler's marginal-cost pricing divides per slot."""
         return self._decode_cost()[2]
 
     def fused_step_estimate(self, width: int, chunk: int):
@@ -710,8 +696,8 @@ class ServingEngine:
         the fleet scheduler prices every candidate placement through
         this, so repeat lookups must be dict-cheap."""
         key = ("fused", int(width), int(chunk))
-        hit = self._step_energy_cache.get(key, "miss")
-        if hit != "miss":
+        hit = self._step_energy_cache.get(key)
+        if hit is not None:
             return hit
         from repro.core.energy import fused_step_energy
         from repro.models.config import (collective_wire_bytes,
@@ -1449,7 +1435,7 @@ class ServingEngine:
             self.pretuned = ops.warm_gemm_cache(
                 fleet, dtype=self.cfg.activation_dtype,
                 objective=objective, chip=self.chip,
-                rank_mode=rank_mode, strict=True)
+                rank_mode=rank_mode)
             self.tuning_degraded = False
             self._degraded_reason = None
         except ArtifactError as e:

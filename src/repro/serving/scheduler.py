@@ -496,7 +496,7 @@ class FleetScheduler:
                          chunk_calls=n_calls, chunk_width=width,
                          decode_steps=budget, decode_batch=eng.max_batch,
                          tokens=budget)
-        step_s = fused.step_s if fused is not None else 0.0
+        step_s = fused.step_s
         backlog_calls = eng.backlog_tokens / max(width * bucket, 1)
         start = max(m.elapsed, now)
         ttft = (start - now) + (n_calls + backlog_calls) * step_s
@@ -748,8 +748,7 @@ class FleetScheduler:
                 return None
             width = _pow2ceil(len(pending))
             for bucket in ladder:
-                est = eng.fused_step_estimate(width, bucket)
-                step_s = est.step_s if est is not None else 0.0
+                step_s = eng.fused_step_estimate(width, bucket).step_s
                 if all(now + math.ceil(rem / bucket) * step_s <= dl
                        for dl, rem in deadlines):
                     return bucket
@@ -764,8 +763,6 @@ class FleetScheduler:
         step's energy split per slot) — the expense ranking the drain
         decision uses."""
         est = m.engine.decode_step_estimate()
-        if est is None:
-            return 0.0
         return est.energy_j / max(m.engine.max_batch, 1)
 
     def _outstanding_deadlines(self) -> list[tuple[float, float]]:
@@ -802,7 +799,7 @@ class FleetScheduler:
             bucket = self._buckets(eng)[-1]
             width = _pow2ceil(eng.lane_width)
             est = eng.fused_step_estimate(width, bucket)
-            if est is not None and est.step_s > 0.0:
+            if est.step_s > 0.0:
                 rate += width * bucket / est.step_s
         if rate <= 0.0:
             return False

@@ -14,7 +14,8 @@ import numpy as np
 import pytest
 
 from repro.core.autotuner import GemmAutotuner, build_default_predictor
-from repro.core.chips import RTX_4070, TPU_V5E, available_chips, get_chip
+from repro.core.chips import (RTX_4070, TPU_V5E, available_chips,
+                              chip_for_device_kind, get_chip)
 from repro.core.features import (
     NUMERIC_FEATURES,
     config_features,
@@ -130,6 +131,11 @@ class TestChipRegistry:
     def test_unknown_chip_raises(self):
         with pytest.raises(ValueError, match="unknown chip"):
             get_chip("h100")
+
+    def test_device_kind_resolves_or_raises(self):
+        assert chip_for_device_kind("TPU v5 lite") is TPU_V5E
+        with pytest.raises(ValueError, match="no chip spec"):
+            chip_for_device_kind("TPU v4")
 
     def test_rtx4070_spec_matches_paper(self):
         assert RTX_4070.ridge_point("f32") == pytest.approx(57.8, rel=0.02)

@@ -423,3 +423,34 @@ class TestWarmGemmCache:
         finally:
             at.set_tuner(None)
             ops._tuned_config.cache_clear()
+
+    def test_tuner_failure_raises_through_matmul(self):
+        """A tuner that cannot rank must stop the trace, not hand the
+        kernel an untuned DEFAULT_CONFIG."""
+        import jax.numpy as jnp
+
+        from repro.core import autotuner as at
+        from repro.kernels import ops
+
+        class Broken:
+            chip = get_chip("tpu_v5e")
+
+            def best_config(self, *a, **kw):
+                raise RuntimeError("predictor unavailable")
+
+            tune_many = best_config
+
+        at.set_tuner(Broken(), chip="tpu_v5e")
+        ops._tuned_config.cache_clear()
+        ops.force_mode("pallas_interpret")
+        try:
+            a = jnp.ones((24, 136), jnp.float32)
+            b = jnp.ones((136, 40), jnp.float32)
+            with pytest.raises(RuntimeError, match="predictor unavailable"):
+                ops.matmul(a, b)
+            with pytest.raises(RuntimeError, match="predictor unavailable"):
+                ops.warm_gemm_cache([(24, 40, 136)], dtype="float32")
+        finally:
+            ops.force_mode("auto")
+            at.set_tuner(None)
+            ops._tuned_config.cache_clear()
