@@ -46,6 +46,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 
@@ -369,25 +370,33 @@ class ServingEngine:
                 fleet, dtype=cfg.activation_dtype,
                 objective=tune_objective, chip=chip,
                 rank_mode=tune_rank_mode)
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, cfg, max_len=max_len))
+        # the engine's programs are named functions, so a device trace
+        # names their executions `jit_engine_<call>`
+        def engine_prefill(p, b):
+            return model.prefill(p, b, cfg, max_len=max_len)
+
+        def engine_decode(p, t, s):
+            return model.decode_step(p, t, s, cfg)
+
+        def engine_chunk(p, t, ln, s):
+            return model.prefill_chunk(p, t, ln, s, cfg)
+
+        def engine_admit(p, pk, s):
+            return model.admit(p, pk, s, cfg)
+
+        self._prefill = jax.jit(engine_prefill)
         # decode/chunk/splice rebind their state output over the input:
         # donating the input state lets XLA update the KV caches in place
         # instead of copying the whole decode state every step
-        self._decode = jax.jit(
-            lambda p, t, s: model.decode_step(p, t, s, cfg),
-            donate_argnums=(2,))
-        self._chunk = (jax.jit(
-            lambda p, t, ln, s: model.prefill_chunk(p, t, ln, s, cfg),
-            donate_argnums=(3,))
-            if model.prefill_chunk is not None else None)
+        self._decode = jax.jit(engine_decode, donate_argnums=(2,))
+        self._chunk = (jax.jit(engine_chunk, donate_argnums=(3,))
+                       if model.prefill_chunk is not None else None)
         # prefill-once admission call for admit families (encdec source
         # encoding + cross-KV, vlm patch prefix); batch-generic, donates
         # the state it writes into
-        self._admit_fn = (jax.jit(
-            lambda p, pk, s: model.admit(p, pk, s, cfg),
-            donate_argnums=(2,))
-            if getattr(model, "admit", None) is not None else None)
+        self._admit_fn = (jax.jit(engine_admit, donate_argnums=(2,))
+                          if getattr(model, "admit", None) is not None
+                          else None)
         self._splice_fn = None          # built lazily with the axes spec
         self._state_axes = None
         # model clock: predicted seconds of dispatched engine calls (the
@@ -432,9 +441,11 @@ class ServingEngine:
             "lane_rebuilds": 0,
             # fault-recovery ledger: energy a failed attempt spent on
             # work that had to be replayed (charged here, to the failed
-            # member, never to the request's final Result) and rows this
-            # engine adopted from a failed member
-            "lost_energy_j": 0.0, "adopted_in": 0,
+            # member, never to the request's final Result)
+            "lost_energy_j": 0.0,
+            # chunk calls: prompt tokens they prefilled (sum of lens) and
+            # token positions they computed (sum of width x bucket)
+            "chunk_prompt_tokens": 0, "chunk_token_slots": 0,
         }
 
     # ------------------------------------------------------------------
@@ -815,10 +826,12 @@ class ServingEngine:
             lambda: self.model.init_state(self.cfg, 2, self.max_len))
         axes = L.state_batch_axes(s1, s2)
         self._state_axes = axes
-        self._splice_fn = jax.jit(
-            lambda dst, src, i, j: L.insert_slot_state(
-                dst, L.take_slot_state(src, axes, i), axes, j),
-            donate_argnums=(0,))
+
+        def engine_splice(dst, src, i, j):
+            return L.insert_slot_state(dst, L.take_slot_state(src, axes, i),
+                                       axes, j)
+
+        self._splice_fn = jax.jit(engine_splice, donate_argnums=(0,))
 
     def _admit_rows(self, reqs: list[Request], width: int
                     ) -> tuple[dict, float]:
@@ -900,36 +913,41 @@ class ServingEngine:
         active = np.array([s is not None for s in slots])
         if not active.any():
             return batch_state
-        self._tick(decode_step_s, decode_est)
-        logits, batch_state = self._decode(
-            self.params, jnp.asarray(token_buf), batch_state)
-        logits = np.asarray(logits, np.float32)
-        cur = self._sample(
-            logits, [s.rng if s is not None else None for s in slots])
-        now = time.perf_counter()
         n_active = int(active.sum())
-        self._stats["decode_steps"] += 1
-        self._stats["slot_steps"] += B
-        self._stats["resident_slot_steps"] += n_active
-        # dead slots still execute: their energy share is real spend,
-        # charged to the engine (idle) rather than to any request, so
-        # report()'s J/token stays comparable with wave mode
-        self._stats["idle_energy_j"] += (
-            (B - n_active) * decode_energy_j / B)
-        for b in range(B):
-            slot = slots[b]
-            if slot is None:
-                continue
-            tok = int(cur[b])
-            slot.tokens.append(tok)
-            slot.steps += 1
-            token_buf[b] = tok
-            req = slot.req
-            if (req.eos_id is not None and tok == req.eos_id) or (
-                    len(slot.tokens) >= self._budget(req)):
-                self._finish(slot, now, decode_energy_j, results)
-                slots[b] = None      # retired mid-decode; refilled
-                token_buf[b] = 0     # next loop iteration
+        with TraceAnnotation("engine.decode", rows=n_active):
+            self._tick(decode_step_s, decode_est)
+            logits, batch_state = self._decode(
+                self.params, jnp.asarray(token_buf), batch_state)
+            with TraceAnnotation("engine.fetch"):
+                logits = np.asarray(logits, np.float32)
+            with TraceAnnotation("engine.sample"):
+                cur = self._sample(
+                    logits, [s.rng if s is not None else None
+                             for s in slots])
+                now = time.perf_counter()
+                self._stats["decode_steps"] += 1
+                self._stats["slot_steps"] += B
+                self._stats["resident_slot_steps"] += n_active
+                # dead slots still execute: their energy share is real
+                # spend, charged to the engine (idle) rather than to any
+                # request, so report()'s J/token stays comparable with
+                # wave mode
+                self._stats["idle_energy_j"] += (
+                    (B - n_active) * decode_energy_j / B)
+                for b in range(B):
+                    slot = slots[b]
+                    if slot is None:
+                        continue
+                    tok = int(cur[b])
+                    slot.tokens.append(tok)
+                    slot.steps += 1
+                    token_buf[b] = tok
+                    req = slot.req
+                    if (req.eos_id is not None and tok == req.eos_id) or (
+                            len(slot.tokens) >= self._budget(req)):
+                        self._finish(slot, now, decode_energy_j, results)
+                        slots[b] = None      # retired mid-decode; refilled
+                        token_buf[b] = 0     # next loop iteration
         return batch_state
 
     def run_continuous(self) -> list[Result]:
@@ -1049,7 +1067,6 @@ class ServingEngine:
                              t_start=now, t_first=now,
                              t_first_model=self._clock,
                              rng=rec.get("rng"))
-                self._stats["adopted_in"] += 1
                 tok = slot.tokens[-1]
                 if (req.eos_id is not None and tok == req.eos_id) or (
                         len(slot.tokens) >= self._budget(req)):
@@ -1082,13 +1099,10 @@ class ServingEngine:
                 lv.token_buf[b] = a.first_tok
             lv.adm = keep
 
-        def chunk_stage() -> bool:
-            """Run one chunk call over the rows still prefilling (parked
-            and vacant rows ride along as zero-length identity rows).
-            Samples first tokens for rows whose last chunk landed.
-            Returns True when a request finished outright on its first
-            sampled token (a lane row freed — the caller re-admits in
-            the same pass)."""
+        def lane_stage() -> None:
+            """Give every new admission a lane row: grow the lane when
+            more rows are in flight than it holds, zero reused rows, and
+            run the prefill-once admission call of admit families."""
             W = lv.adm_w or 1
             while W < len(lv.adm):
                 W *= 2
@@ -1096,19 +1110,20 @@ class ServingEngine:
                 # width growth (or first build): reallocate, carrying
                 # every in-progress row across *at its own index* — row
                 # assignments are sticky so no repacking splices happen
-                new_state = self._init_state(W)
-                held = set()
-                for a in lv.adm:
-                    if a.row >= 0:
-                        held.add(a.row)
-                        if a.base > 0:
-                            new_state = self._splice_fn(
-                                new_state, lv.adm_state, jnp.int32(a.row),
-                                jnp.int32(a.row))
-                lv.adm_state, lv.adm_w = new_state, W
-                lv.lane_free = [r for r in range(W) if r not in held]
-                lv.lane_dirty.clear()
-                self._stats["lane_rebuilds"] += 1
+                with TraceAnnotation("engine.lane_grow"):
+                    new_state = self._init_state(W)
+                    held = set()
+                    for a in lv.adm:
+                        if a.row >= 0:
+                            held.add(a.row)
+                            if a.base > 0:
+                                new_state = self._splice_fn(
+                                    new_state, lv.adm_state,
+                                    jnp.int32(a.row), jnp.int32(a.row))
+                    lv.adm_state, lv.adm_w = new_state, W
+                    lv.lane_free = [r for r in range(W) if r not in held]
+                    lv.lane_dirty.clear()
+                    self._stats["lane_rebuilds"] += 1
             lv.lane_free.sort()
             fresh: list[_Admission] = []
             for a in lv.adm:
@@ -1142,6 +1157,15 @@ class ServingEngine:
                     a.prefix = self._admit_dims(a.req)[0]
                     if a.t_start == 0.0:
                         a.t_start = t_adm
+
+        def chunk_stage() -> bool:
+            """Run one chunk call over the rows still prefilling (parked
+            and vacant rows ride along as zero-length identity rows).
+            Samples first tokens for rows whose last chunk landed.
+            Returns True when a request finished outright on its first
+            sampled token (a lane row freed — the caller re-admits in
+            the same pass)."""
+            W = lv.adm_w
             pending = [a for a in lv.adm if a.ready is None]
             rem = [len(a.eff) - a.base for a in pending]
             # shortest-remainder-first bucket: short admissions finish in
@@ -1168,6 +1192,16 @@ class ServingEngine:
                 # aligned one (validated to exist at construction)
                 while C % self.ssm_grain:
                     C = self._chunk_bucket(C // 2)
+            n_tok = sum(min(C, r) for r in rem)
+            with TraceAnnotation("engine.chunk", width=W, bucket=C,
+                                 rows=len(pending), tokens=n_tok):
+                return chunk_call(W, C, pending, n_tok)
+
+        def chunk_call(W: int, C: int, pending: list[_Admission],
+                       n_tok: int) -> bool:
+            """`chunk_stage`'s call of width `W` and bucket `C` over the
+            `pending` rows (`n_tok` prompt tokens) and its first-token
+            bookkeeping."""
             toks = np.zeros((W, C), np.int32)
             lens = np.zeros(W, np.int32)
             t_disp = time.perf_counter()
@@ -1180,57 +1214,62 @@ class ServingEngine:
             logits, lv.adm_state = self._chunk(
                 self.params, jnp.asarray(toks), jnp.asarray(lens),
                 lv.adm_state)
-            logits = np.asarray(logits, np.float32)
+            with TraceAnnotation("engine.fetch"):
+                logits = np.asarray(logits, np.float32)
             now = time.perf_counter()
             est_j, est_s, est = self._chunk_cost(W, C)
             self._tick(est_s, est)
             self._stats["chunk_steps"] += 1
+            self._stats["chunk_prompt_tokens"] += n_tok
+            self._stats["chunk_token_slots"] += W * C
             # lane pad/parked rows are executed spend with no owner
             self._stats["idle_energy_j"] += (W - len(pending)) * est_j / W
             keep: list[_Admission] = []
             freed = False
-            for a in lv.adm:
-                if a.ready is not None:
+            with TraceAnnotation("engine.sample"):
+                for a in lv.adm:
+                    if a.ready is not None:
+                        keep.append(a)
+                        continue
+                    a.base += int(lens[a.row])
+                    a.chunk_energy_j += est_j / W
+                    if a.base < len(a.eff):
+                        keep.append(a)
+                        continue
+                    replay = a.req.replay
+                    if replay:
+                        # fault replay: the emitted prefix is forced, not
+                        # resampled — the stream stays an exact
+                        # append-only continuation. Non-greedy rows burn
+                        # the failed attempt's Gumbel draws so later
+                        # tokens keep bit parity with the no-fault run.
+                        if a.rng is not None:
+                            for _ in replay:
+                                a.rng.gumbel(size=logits.shape[-1])
+                        tok = int(replay[-1])
+                        toks0 = [int(t) for t in replay]
+                    else:
+                        tok = int(self._sample(logits[a.row:a.row + 1],
+                                               [a.rng])[0])
+                        toks0 = [tok]
+                    srec = _Slot(req=a.req, tokens=toks0,
+                                 prefill_energy_j=a.chunk_energy_j,
+                                 t_start=a.t_start, t_first=now,
+                                 t_first_model=self._clock, rng=a.rng)
+                    # EOS or an exhausted budget on the first (or last
+                    # replayed) token: finished before occupying a decode
+                    # slot
+                    if (a.req.eos_id is not None
+                            and tok == a.req.eos_id) or (
+                            len(toks0) >= self._budget(a.req)):
+                        self._finish(srec, now, decode_energy_j, results)
+                        lv.lane_free.append(a.row)
+                        lv.lane_dirty.add(a.row)
+                        freed = True
+                        continue
+                    a.ready = srec
+                    a.first_tok = tok
                     keep.append(a)
-                    continue
-                a.base += int(lens[a.row])
-                a.chunk_energy_j += est_j / W
-                if a.base < len(a.eff):
-                    keep.append(a)
-                    continue
-                replay = a.req.replay
-                if replay:
-                    # fault replay: the emitted prefix is forced, not
-                    # resampled — the stream stays an exact append-only
-                    # continuation. Non-greedy rows burn the failed
-                    # attempt's Gumbel draws so later tokens keep bit
-                    # parity with the no-fault run.
-                    if a.rng is not None:
-                        for _ in replay:
-                            a.rng.gumbel(size=logits.shape[-1])
-                    tok = int(replay[-1])
-                    toks0 = [int(t) for t in replay]
-                else:
-                    tok = int(self._sample(logits[a.row:a.row + 1],
-                                           [a.rng])[0])
-                    toks0 = [tok]
-                srec = _Slot(req=a.req, tokens=toks0,
-                             prefill_energy_j=a.chunk_energy_j,
-                             t_start=a.t_start, t_first=now,
-                             t_first_model=self._clock, rng=a.rng)
-                # EOS or an exhausted budget on the first (or last
-                # replayed) token: finished before occupying a decode
-                # slot
-                if (a.req.eos_id is not None and tok == a.req.eos_id) or (
-                        len(toks0) >= self._budget(a.req)):
-                    self._finish(srec, now, decode_energy_j, results)
-                    lv.lane_free.append(a.row)
-                    lv.lane_dirty.add(a.row)
-                    freed = True
-                    continue
-                a.ready = srec
-                a.first_tok = tok
-                keep.append(a)
             lv.adm = keep
             if not lv.adm:
                 lv.adm_state, lv.adm_w = None, 0
@@ -1242,43 +1281,52 @@ class ServingEngine:
         while (self.queue or self._adopted or lv.adm
                or any(s is not None for s in lv.slots)):
             t_it0 = time.perf_counter()
-            # ---- adopt + admit + chunk: splice adopted rows and fill
-            # free lane rows from the queue, then run one chunk call; a
-            # request finishing on its first sampled token frees its
-            # lane row again, so keep admitting until the lane is full
-            # of live work or the queue drains ----
-            adopt_ready()
-            splice_ready()
-            while True:
-                while self.queue and len(lv.adm) < self.lane_width:
-                    req = self.queue.popleft()
-                    rng = None if self.greedy else self._req_rng(req.uid)
-                    eff = np.asarray(req.prompt, np.int32)
-                    if req.replay and len(req.replay) > 1:
-                        eff = np.concatenate(
-                            [eff, np.asarray(req.replay[:-1], np.int32)])
-                    lv.adm.append(_Admission(req=req, rng=rng, eff=eff))
-                if not any(a.ready is None for a in lv.adm):
-                    break
-                freed = chunk_stage()
-                if not (freed and self.queue):
-                    break
-            adopt_ready()
-            splice_ready()
-            # ---- one lockstep decode step over the residents ----
-            lv.batch_state = self._decode_step(
-                lv.slots, lv.batch_state, lv.token_buf, decode_cost,
-                results)
-            self._stats["wall_s"] += time.perf_counter() - t_it0
-            pending_n = sum(a.ready is None for a in lv.adm)
-            self._lane_view = {
-                "pending": pending_n,
-                "pending_tokens": sum(len(a.eff) - a.base
-                                      for a in lv.adm if a.ready is None),
-                "parked": len(lv.adm) - pending_n,
-                "resident": sum(s is not None for s in lv.slots),
-                "in_flight": len(lv.adm),
-            }
+            with TraceAnnotation("engine.step"):
+                # ---- adopt + admit + chunk: splice adopted rows and
+                # fill free lane rows from the queue, then run one chunk
+                # call; a request finishing on its first sampled token
+                # frees its lane row again, so keep admitting until the
+                # lane is full of live work or the queue drains ----
+                with TraceAnnotation("engine.splice"):
+                    adopt_ready()
+                    splice_ready()
+                while True:
+                    with TraceAnnotation("engine.admit"):
+                        while self.queue and len(lv.adm) < self.lane_width:
+                            req = self.queue.popleft()
+                            rng = (None if self.greedy
+                                   else self._req_rng(req.uid))
+                            eff = np.asarray(req.prompt, np.int32)
+                            if req.replay and len(req.replay) > 1:
+                                eff = np.concatenate(
+                                    [eff, np.asarray(req.replay[:-1],
+                                                     np.int32)])
+                            lv.adm.append(
+                                _Admission(req=req, rng=rng, eff=eff))
+                        if not any(a.ready is None for a in lv.adm):
+                            break
+                        lane_stage()
+                    freed = chunk_stage()
+                    if not (freed and self.queue):
+                        break
+                with TraceAnnotation("engine.splice"):
+                    adopt_ready()
+                    splice_ready()
+                # ---- one lockstep decode step over the residents ----
+                lv.batch_state = self._decode_step(
+                    lv.slots, lv.batch_state, lv.token_buf, decode_cost,
+                    results)
+                self._stats["wall_s"] += time.perf_counter() - t_it0
+                pending_n = sum(a.ready is None for a in lv.adm)
+                self._lane_view = {
+                    "pending": pending_n,
+                    "pending_tokens": sum(len(a.eff) - a.base
+                                          for a in lv.adm
+                                          if a.ready is None),
+                    "parked": len(lv.adm) - pending_n,
+                    "resident": sum(s is not None for s in lv.slots),
+                    "in_flight": len(lv.adm),
+                }
             new, emitted = results[emitted:], len(results)
             yield new
         self._live = None
@@ -1481,9 +1529,11 @@ class ServingEngine:
             self._pool = jax.device_put(
                 self._pool,
                 serving_state_shardings(self._pool, self.mesh))
-        self._copy_pages = jax.jit(
-            lambda pool, src, dst: L.copy_pool_pages(pool, src, dst),
-            donate_argnums=(0,))
+
+        def engine_copy_pages(pool, src, dst):
+            return L.copy_pool_pages(pool, src, dst)
+
+        self._copy_pages = jax.jit(engine_copy_pages, donate_argnums=(0,))
 
     def _run_paged(self) -> list[Result]:
         """Chunked-admission continuous batching over the paged KV layout.
@@ -1733,6 +1783,8 @@ class ServingEngine:
             est_j, est_s, est = self._chunk_cost(W, C)
             self._tick(est_s, est)
             self._stats["chunk_steps"] += 1
+            self._stats["chunk_prompt_tokens"] += int(lens.sum())
+            self._stats["chunk_token_slots"] += W * C
             self._stats["idle_energy_j"] += (W - len(pending)) * est_j / W
             keep: list[_Admission] = []
             freed = False
@@ -2057,6 +2109,8 @@ class ServingEngine:
             "generated_tokens": toks,
             "decode_steps": s["decode_steps"],
             "chunk_steps": s["chunk_steps"],
+            "chunk_prompt_tokens": s["chunk_prompt_tokens"],
+            "chunk_token_slots": s["chunk_token_slots"],
             "slot_steps": slot_steps,
             "resident_slot_steps": s["resident_slot_steps"],
             "slot_occupancy": (s["resident_slot_steps"] / slot_steps
@@ -2068,10 +2122,9 @@ class ServingEngine:
             "idle_energy_j": s["idle_energy_j"],
             "j_per_token": total_j / toks if toks else 0.0,
             # robustness surface: replayed work charged to this engine
-            # as the failed attempt, rows adopted from failed members,
-            # and whether tuning fell back to BASELINE configs
+            # as the failed attempt, and whether tuning fell back to
+            # BASELINE configs
             "lost_energy_j": s["lost_energy_j"],
-            "adopted_in": s["adopted_in"],
             "tuning_degraded": self.tuning_degraded,
             "tuning_degraded_reason": self._degraded_reason,
         }
