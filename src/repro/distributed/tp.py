@@ -329,11 +329,14 @@ def replicate_for_parity(x: jax.Array, cfg) -> jax.Array:
 
 def tp_column(x, w, cfg, out_dtype=None):
     """x @ w, column-parallel under explicit TP (output cast to
-    `out_dtype`, default x's dtype)."""
-    if not tp_enabled(cfg):
-        from repro.kernels import ops
+    `out_dtype`, default x's dtype). `w` may be an `ops.WeightRef`: read
+    in place without TP, sliced out of its stack before the shard_map
+    with it."""
+    from repro.kernels import ops
 
+    if not tp_enabled(cfg):
         return ops.matmul(x, w, out_dtype=out_dtype)
+    w = ops.resolve(w)
     mesh = current_mesh()
     if w.shape[-1] % mesh.shape["model"] == 0:
         ctx = (mesh,) if out_dtype is None else (mesh, jnp.dtype(out_dtype))
@@ -342,7 +345,11 @@ def tp_column(x, w, cfg, out_dtype=None):
 
 
 def tp_row(x, w, cfg):
+    """x @ w, row-parallel under explicit TP; `w` as in `tp_column`."""
+    from repro.kernels import ops
+
     if tp_enabled(cfg):
+        w = ops.resolve(w)
         mesh = current_mesh()
         tp = mesh.shape["model"]
         chunks = max(int(getattr(cfg, "tp_overlap_chunks", 1)), 1)
@@ -353,5 +360,4 @@ def tp_row(x, w, cfg):
                 and w.shape[0] % tp == 0:
             return row_parallel(x, w, (mesh, chunks))
         return _replicated_dot(x, w, mesh)
-    from repro.kernels import ops
     return ops.matmul(x, w)

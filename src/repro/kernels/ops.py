@@ -11,10 +11,16 @@ dispatches per backend:
     is TPU-target-only and is validated separately in interpret mode.
 
 Set `force_mode("pallas_interpret")` in tests to route through the kernel.
+
+A weight may be given as a `WeightRef`: one layer of a weight stacked over
+layers, named by a traced index. The Pallas kernel reads that layer where
+it lives in the stack; the XLA path slices it out first.
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import functools
 from typing import Literal
 
@@ -27,6 +33,55 @@ _MODE: Literal["auto", "pallas", "pallas_interpret", "xla"] = "auto"
 _CHIP: str = "tpu_v5e"
 # (m, n, k, dtype) -> BlockConfig of every kernel call traced so far
 _TRACED: dict[tuple[int, int, int, str], BlockConfig] = {}
+# where `weight_reads` counts the Pallas GEMM calls being traced
+_READS: list[dict[str, int]] = []
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class WeightRef:
+    """A GEMM weight read where it lives: the parameter `array` itself, or
+    with `index` (an integer scalar, usually traced) layer `index` of a
+    weight stacked over layers, `array` (L, K, N) ((L, N, K) for
+    `transpose_b`). `matmul` hands the Pallas kernel the whole stack and
+    the index, so the layer is never copied out of the stack first;
+    every other reader takes `resolve(ref)`."""
+
+    array: jax.Array
+    index: jax.Array | None = None
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return self.array.shape if self.index is None else self.array.shape[1:]
+
+    @property
+    def dtype(self):
+        return self.array.dtype
+
+
+def resolve(w):
+    """The array a weight stands for: a `WeightRef`'s parameter, or its
+    layer sliced out of the stack; anything else as it is."""
+    if not isinstance(w, WeightRef):
+        return w
+    if w.index is None:
+        return w.array
+    return jax.lax.dynamic_index_in_dim(w.array, w.index, keepdims=False)
+
+
+@contextlib.contextmanager
+def weight_reads(counts: dict[str, int]):
+    """Count into `counts` each Pallas GEMM call traced inside the block
+    by how its weight was read: ``in_place`` (a `WeightRef`) or
+    ``staged`` (a plain array: a slice the caller made of a parameter, or
+    any other array computed before the call)."""
+    counts.setdefault("in_place", 0)
+    counts.setdefault("staged", 0)
+    _READS.append(counts)
+    try:
+        yield counts
+    finally:
+        _READS.pop()
 
 
 def force_mode(mode: Literal["auto", "pallas", "pallas_interpret", "xla"]):
@@ -252,14 +307,15 @@ def warm_fleet_gemm_cache(specs, *, objective: str = "runtime",
 
 def matmul(
     a: jax.Array,
-    b: jax.Array,
+    b: jax.Array | WeightRef,
     *,
     config: BlockConfig | None = None,
     objective: str = "runtime",
     transpose_b: bool = False,
     out_dtype=None,
 ) -> jax.Array:
-    """out = a @ op(b) over the last axis of `a`; leading dims are batch."""
+    """out = a @ op(b) over the last axis of `a`; leading dims are batch.
+    `b` may be a `WeightRef`, which the Pallas kernel reads in place."""
     *lead, k = a.shape
     if transpose_b:
         n, kb = b.shape
@@ -275,13 +331,18 @@ def matmul(
     if mode == "xla":
         dn = (((1,), (1 if transpose_b else 0,)), ((), ()))
         out = jax.lax.dot_general(
-            a.reshape(m, k), b, dn, preferred_element_type=jnp.float32
+            a.reshape(m, k), resolve(b), dn,
+            preferred_element_type=jnp.float32
         ).astype(out_dtype)
     else:
         cfg = config or _tuned_config(m, n, k, str(a.dtype), objective, _CHIP)
         _TRACED[(m, n, k, str(a.dtype))] = cfg
+        ref = isinstance(b, WeightRef)
+        if _READS:
+            _READS[-1]["in_place" if ref else "staged"] += 1
         out = tiled_matmul(
-            a.reshape(m, k), b,
+            a.reshape(m, k), b.array if ref else b,
+            layer=b.index if ref else None,
             config=cfg,
             transpose_b=transpose_b,
             out_dtype=out_dtype,
@@ -290,9 +351,9 @@ def matmul(
     return out.reshape(*lead, n)
 
 
-def linear(x: jax.Array, w: jax.Array, b: jax.Array | None = None,
-           **kw) -> jax.Array:
-    """y = x @ w (+ b). w: (K, N)."""
+def linear(x: jax.Array, w: jax.Array | WeightRef,
+           b: jax.Array | None = None, **kw) -> jax.Array:
+    """y = x @ w (+ b). w: (K, N), or a `WeightRef` to one."""
     y = matmul(x, w, **kw)
     if b is not None:
         y = y + b.astype(y.dtype)

@@ -252,7 +252,8 @@ def moe_ffn_shard_map(p: Params, x: jax.Array, cfg: ModelConfig
     shared_args = None
     if cfg.n_shared_experts:
         sm = p["shared_mlp"]
-        shared_args = (sm["w_gate"], sm["w_up"], sm["w_down"])
+        shared_args = tuple(ops.resolve(sm[k])
+                            for k in ("w_gate", "w_up", "w_down"))
     batch_ax = dp_ax if len(dp_ax) > 1 else dp_ax[0]
     fn = shard_map(
         local_fn,
@@ -266,8 +267,8 @@ def moe_ffn_shard_map(p: Params, x: jax.Array, cfg: ModelConfig
         out_specs=(P_(batch_ax, None, None), P_()),
         check_vma=False,
     )
-    return fn(x, p["router"]["w"], w["w_gate"], w["w_up"], w["w_down"],
-              shared_args)
+    return fn(x, ops.resolve(p["router"]["w"]), w["w_gate"], w["w_up"],
+              w["w_down"], shared_args)
 
 
 def moe_ffn(p: Params, x: jax.Array, cfg: ModelConfig):

@@ -57,6 +57,7 @@ def serve_chunk(cfg: ModelConfig) -> int:
 def causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
                 history: jax.Array | None = None) -> jax.Array:
     """x: (B, S, C); w: (C, K); history: (B, K-1, C) carried state."""
+    w = ops.resolve(w)
     B, S, C = x.shape
     K = w.shape[1]
     if history is None:
@@ -191,7 +192,7 @@ def _mamba1_core(p: Params, x_conv: jax.Array, cfg: ModelConfig,
     dt_low, Bm, Cm = jnp.split(proj, [r, r + ds], axis=-1)
     dtv = ops.matmul(dt_low, s["dt_proj"]).astype(jnp.float32)
     dtv = jax.nn.softplus(dtv + s["dt_bias"].astype(jnp.float32))  # (B,S,di)
-    A = -jnp.exp(s["A_log"].astype(jnp.float32))                   # (di,ds)
+    A = -jnp.exp(ops.resolve(s["A_log"]).astype(jnp.float32))      # (di,ds)
     decay = jnp.exp(dtv[..., None] * A)                            # (B,S,di,ds)
     xf = x_conv.astype(jnp.float32)
     inp = (dtv * xf)[..., None] * Bm.astype(jnp.float32)[:, :, None, :]

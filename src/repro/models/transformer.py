@@ -110,10 +110,11 @@ def _embed(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 def _unembed(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     if cfg.tie_embeddings:
-        return ops.matmul(x, params["embed"]["table"], transpose_b=True,
-                          out_dtype=jnp.float32)
+        return ops.matmul(x, ops.WeightRef(params["embed"]["table"]),
+                          transpose_b=True, out_dtype=jnp.float32)
     # vocab-parallel under explicit TP (the head is sharded on vocab)
-    return tp_column(x, params["head"]["w"], cfg, out_dtype=jnp.float32)
+    return tp_column(x, ops.WeightRef(params["head"]["w"]), cfg,
+                     out_dtype=jnp.float32)
 
 
 def _scan_blocks(params: Params, x: jax.Array, cfg: ModelConfig,
@@ -121,7 +122,21 @@ def _scan_blocks(params: Params, x: jax.Array, cfg: ModelConfig,
                  cache_index=None):
     """Run stacked blocks via lax.scan; threads per-layer cache if given.
 
+    Without a cache (the training loss) the scan slices every stacked
+    leaf, the plain path its gradient takes. With one (the serving
+    programs) it scans the layer index with the blocks closed over: each
+    GEMM weight, a (K, N) matrix per layer, reaches the block as an
+    `ops.WeightRef` that the tiled GEMM reads in place, and every other
+    leaf (norm scales, biases, expert stacks) is sliced as the scan would.
+
     Returns (x, new_caches, total_aux_loss)."""
+    blocks = params["blocks"]
+
+    def layer(i):
+        return jax.tree.map(
+            lambda w: (ops.WeightRef(w, i) if w.ndim == 3 else
+                       jax.lax.dynamic_index_in_dim(w, i, keepdims=False)),
+            blocks)
 
     def body(carry, inp):
         h, aux_acc = carry
@@ -129,15 +144,20 @@ def _scan_blocks(params: Params, x: jax.Array, cfg: ModelConfig,
             blk = inp
             h, _, aux = block_apply(blk, h, cfg, positions=positions)
             return (h, aux_acc + aux), None
-        blk, layer_cache = inp
-        h, new_cache, aux = block_apply(blk, h, cfg, positions=positions,
+        i, layer_cache = inp
+        h, new_cache, aux = block_apply(layer(i), h, cfg,
+                                        positions=positions,
                                         cache=layer_cache,
                                         cache_index=cache_index)
         return (h, aux_acc + aux), new_cache
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    xs = params["blocks"] if cache is None else (params["blocks"], cache)
+    if cache is None:
+        xs = blocks
+    else:
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        xs = (jnp.arange(n, dtype=jnp.int32), cache)
     (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
     return x, caches, aux
 

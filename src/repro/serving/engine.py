@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import time
 from collections import deque
 
@@ -371,18 +372,26 @@ class ServingEngine:
                 objective=tune_objective, chip=chip,
                 rank_mode=tune_rank_mode)
         # the engine's programs are named functions, so a device trace
-        # names their executions `jit_engine_<call>`
+        # names their executions `jit_engine_<call>`; tracing one counts
+        # its Pallas GEMM calls by how each reads its weight
+        self._weight_reads = {"in_place": 0, "staged": 0}
+        reads = functools.partial(ops.weight_reads, self._weight_reads)
+
         def engine_prefill(p, b):
-            return model.prefill(p, b, cfg, max_len=max_len)
+            with reads():
+                return model.prefill(p, b, cfg, max_len=max_len)
 
         def engine_decode(p, t, s):
-            return model.decode_step(p, t, s, cfg)
+            with reads():
+                return model.decode_step(p, t, s, cfg)
 
         def engine_chunk(p, t, ln, s):
-            return model.prefill_chunk(p, t, ln, s, cfg)
+            with reads():
+                return model.prefill_chunk(p, t, ln, s, cfg)
 
         def engine_admit(p, pk, s):
-            return model.admit(p, pk, s, cfg)
+            with reads():
+                return model.admit(p, pk, s, cfg)
 
         self._prefill = jax.jit(engine_prefill)
         # decode/chunk/splice rebind their state output over the input:
@@ -2105,6 +2114,9 @@ class ServingEngine:
             "overlap_factor": (s["hidden_wire_s"] / s["wire_s"]
                                if s["wire_s"] > 0 else 0.0),
             "lane_rebuilds": s["lane_rebuilds"],
+            # Pallas GEMM calls in the programs traced so far, by how each
+            # read its weight: in place, or from a copy made before it
+            "weight_reads": dict(self._weight_reads),
             "requests": s["requests"],
             "generated_tokens": toks,
             "decode_steps": s["decode_steps"],

@@ -449,6 +449,34 @@ class TestTelemetryAndEnergy:
             rep["attributed_energy_j"] + rep["idle_energy_j"])
         assert rep["idle_energy_j"] >= 0
 
+    def test_weight_reads_in_place_under_pallas(self, served, monkeypatch):
+        """Every Pallas GEMM of the serving programs reads its weight
+        where it lives: each layer's by index into the stacked blocks,
+        the head as it is. None reads a copy made before the call."""
+        from repro.kernels import ops
+        from repro.kernels.tiled_matmul import BlockConfig
+
+        kernel, by_index = ops.tiled_matmul, []
+
+        def counted(*a, **kw):
+            by_index.append(kw.get("layer") is not None)
+            return kernel(*a, **kw)
+
+        monkeypatch.setattr(ops, "tiled_matmul", counted)
+        monkeypatch.setattr(ops, "_tuned_config",
+                            lambda *_: BlockConfig(16, 128, 128))
+        ops.force_mode("pallas_interpret")
+        try:
+            eng = make_engine(served)
+            eng.submit(Request(uid=0, prompt=prompt(0, 11),
+                               max_new_tokens=3))
+            eng.run_until_empty()
+        finally:
+            ops.force_mode("auto")
+        assert eng.report()["weight_reads"] == {"in_place": len(by_index),
+                                                "staged": 0}
+        assert any(by_index) and not all(by_index)
+
     def test_continuous_beats_wave_on_j_per_token(self, served):
         """The Racing-to-Idle claim: on a mixed-budget workload the wave
         engine attributes strictly more energy per generated token."""

@@ -131,6 +131,42 @@ def test_config_invariance():
         np.testing.assert_allclose(outs[0], o, rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("tb", [False, True])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_stacked_weight_read_in_place(layer, tb):
+    """A layer of a stacked weight, named by a traced index, gives what
+    the sliced layer gives, at ragged K and N."""
+    m, n, k = 24, 200, 300
+    a = _rand((m, k), jnp.float32, 21)
+    w = _rand((3, n, k) if tb else (3, k, n), jnp.float32, 22)
+    run = jax.jit(lambda a, w, i: tiled_matmul(
+        a, w, layer=i, config=SMALL, transpose_b=tb, interpret=True))
+    got = run(a, w, jnp.int32(layer))
+    sliced = tiled_matmul(a, w[layer], config=SMALL, transpose_b=tb,
+                          interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(sliced))
+    want = matmul_ref(a, w[layer], transpose_b=tb)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+
+
+# ragged edges at the served widths: starcoder2-3b d_model 3072 (qkv N
+# 3584), OLMoE vocab 50304, each at a block that does not divide it
+@pytest.mark.parametrize("m,n,k,cfg", [
+    (8, 1024, 3072, BlockConfig(8, 1024, 2048)),     # K past the last block
+    (8, 3584, 3072, BlockConfig(8, 1024, 2048)),     # K and N
+    (8, 50304, 256, BlockConfig(8, 1024, 256)),      # N past the last block
+], ids=["k3072_bk2048", "n3584_bn1024", "n50304_bn1024"])
+def test_ragged_edges_at_served_widths(m, n, k, cfg):
+    a = _rand((m, k), jnp.bfloat16, 23)
+    b = _rand((k, n), jnp.bfloat16, 24)
+    got = tiled_matmul(a, b, config=cfg, out_dtype=jnp.float32,
+                       interpret=True)
+    want = matmul_ref(a, b, out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-3)
+
+
 class TestOpsDispatch:
     def test_matmul_batched_lead_dims(self):
         from repro.kernels import ops
@@ -169,6 +205,26 @@ class TestOpsDispatch:
             np.testing.assert_allclose(
                 np.asarray(y), np.asarray(x) @ np.asarray(w), rtol=1e-4,
                 atol=1e-4)
+        finally:
+            ops.force_mode("auto")
+
+    @pytest.mark.parametrize("mode", ["xla", "pallas_interpret"])
+    def test_weight_ref_reads_its_layer(self, mode):
+        from repro.kernels import ops
+
+        ops.force_mode(mode)
+        try:
+            x = _rand((2, 4, 64), jnp.float32, 25)
+            w = _rand((3, 64, 128), jnp.float32, 26)
+            counts = {}
+            with ops.weight_reads(counts):
+                y = jax.jit(lambda x, w, i: ops.matmul(
+                    x, ops.WeightRef(w, i), config=SMALL))(x, w, 2)
+            np.testing.assert_allclose(
+                np.asarray(y), np.asarray(x) @ np.asarray(w[2]), rtol=1e-4,
+                atol=1e-4)
+            reads = 1 if mode != "xla" else 0
+            assert counts == {"in_place": reads, "staged": 0}
         finally:
             ops.force_mode("auto")
 

@@ -162,6 +162,31 @@ class TestTpBitParity:
         """)
         assert "OK" in stdout
 
+    def test_tp_weight_reads_staged(self):
+        """Under explicit TP each weight is sliced out of its stack before
+        the shard_map, so the Pallas calls count it as staged; the same
+        engine at tp=1 reads every weight in place."""
+        stdout = _run_sub("""
+            from repro.kernels import ops
+            from repro.kernels.tiled_matmul import BlockConfig
+
+            ops._tuned_config = lambda *_: BlockConfig(16, 128, 128)
+            ops.force_mode("pallas_interpret")
+            for tp in (1, 2):
+                cfg, model, params = build("dense")
+                eng = ServingEngine(model, params, cfg, max_batch=2,
+                                    max_len=64, tp=tp)
+                eng.submit(reqs(cfg, SPECS[:1])[0])
+                eng.run_until_empty()
+                reads = eng.report()["weight_reads"]
+                print("READS", tp, reads["in_place"], reads["staged"])
+        """)
+        got = {int(tp): (int(i), int(s)) for _, tp, i, s in (
+            ln.split() for ln in stdout.splitlines()
+            if ln.startswith("READS"))}
+        assert got[1][0] > 0 and got[1][1] == 0
+        assert got[2][0] == 0 and got[2][1] > 0
+
     def test_fleet_energy_scales_chips(self):
         """The tp report prices the fleet: per-step estimates carry
         n_chips=tp and J/token strictly above the single-chip run (same
