@@ -3,7 +3,9 @@
 Once the window has closed and the queue has drained, a sample of the
 finished requests, drawn from the seed and holding the longest, is run
 through the plain float32 reference (`bench/reference/<family>.py`):
-one forward pass over each prompt with its served tokens. At each
+one forward pass over each prompt with its served tokens, the family's
+own `forward` where it defines one, else the shared decoder
+(`reference.common.lm_forward`) with its `make_ffn`. At each
 position the gap is how far the served (greedy) token's reference logit
 lies below the reference's best; a cell's `limits` name which of the
 widest gap (`max_logit_gap`) and the mean gap (`mean_logit_gap`) it
@@ -76,9 +78,16 @@ def _scorer(family_mod, m_items: tuple, length: int, n_max: int,
     from reference import common
 
     m = dict(m_items)
-    ffn = family_mod.make_ffn(m)
-    return jax.jit(lambda p, t, at, s: common.gaps(p, t, at, s, m, ffn,
-                                                   control))
+    forward = getattr(family_mod, "forward", None)
+    if forward is None:
+        ffn = family_mod.make_ffn(m)
+
+        def forward(p, t, m, f8):
+            return common.lm_forward(p, t, m, ffn, f8)
+
+    logits = getattr(family_mod, "logits", common.head_logits)
+    return jax.jit(lambda p, t, at, s: common.gaps(p, t, at, s, m, forward,
+                                                   logits, control))
 
 
 def score(family_mod, m: dict, params, picked, results, max_len: int,

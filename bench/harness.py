@@ -7,6 +7,29 @@ Everything a cell needs is found by name: the cell's entry in
 `bench/reference/<family>.py`, `bench/work/<family>.py` and
 `bench/metrics/<metric>.py`. A new cell, mix or metric is new files.
 
+A configuration file names its `family`, and two modules of that name
+stand for it:
+
+- `reference/<family>.py`, the plain float32 reference, which imports
+  nothing of the program. It must define `specs(m)`, `{leaf path:
+  (shape, scale)}` of the parameter tree (the weights both sides get, made
+  by `reference.common.make_params`). It must define either `forward(params,
+  tokens, m, f8)`, the final-normed hidden states (S, d) in float32 of one
+  sequence at positions 0..S-1, or `make_ffn(m)`, the feed-forward of a
+  decoder that `reference.common.lm_forward` runs (GQA attention with
+  rotary positions, one uniform scan over `params["blocks"]`); `forward`
+  wins where both are there. It may define `logits(params, h, f8)`; the
+  default is the untied head, `h @ params["head"]["w"]`. With `f8` every
+  matmul goes through float8, the control of `check.py`.
+- `work/<family>.py`, the operations and bytes a step must spend, from
+  the model's equations. `kernel_gemms(m)`, the (N, K, count) per row and
+  layer that the tiled kernel runs, feeds `tiled_matmul_roofline.*`;
+  `row_flops(m)`, the GEMM operations of one row through every layer,
+  feeds `model_mfu` and `step_mfu.*`; `weight_bytes(m, rows)` feeds
+  `decode_step_hbm_share`. Attention's operations and the cached bytes a
+  position holds come from `work/common.py`'s GQA formulas over
+  `n_layers`, `n_heads`, `n_kv_heads` and `head_dim`, not from the family.
+
 The program is reached only through `repro.configs.get_config`,
 `repro.models.registry.get_model`, `ServingEngine` (`submit`,
 `serve_step`, `has_work`, `queue`, `lane_view`, `backlog_tokens`,

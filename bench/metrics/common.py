@@ -40,6 +40,13 @@ def is_kernel(op) -> bool:
     return any(p in op.text for p in KERNEL_PATTERNS)
 
 
+def in_scope(run, part: str) -> list:
+    """The operations of `run` whose scope (their `op_name` path) holds
+    `part` as one of its `/`-separated components, such as
+    `jit(engine_decode)` or a `jax.named_scope` name."""
+    return [op for op in run if part in op.scope.split("/")]
+
+
 def busy_s(ops) -> float:
     """Seconds in which any of `ops` ran (the union of their intervals)."""
     total, end = 0.0, -math.inf

@@ -161,20 +161,29 @@ def lm_forward(params, tokens, m: dict, ffn, f8: bool) -> jax.Array:
     return rmsnorm(x, params["ln_f"]["scale"], m["norm_eps"])
 
 
-def gaps(params, tokens, at, served, m: dict, ffn, control: bool):
+def head_logits(params, h: jax.Array, f8: bool) -> jax.Array:
+    """Logits of final-normed hidden states through the untied LM head:
+    what a family that defines no `logits` of its own computes."""
+    return mm(h, params["head"]["w"], f8)
+
+
+def gaps(params, tokens, at, served, m: dict, forward, logits,
+         control: bool):
     """For each scored position `at[j]` (the position whose next token is
     `served[j]`): how far the served token's float32 reference logit lies
     below the reference's best. With `control`, also the same gap for the
-    token that the float8 computation puts first. Returns (gap, ctrl_gap),
-    ctrl_gap zeros without `control`."""
+    token that the float8 computation puts first. `forward(params, tokens,
+    m, f8)` gives the family's final-normed hidden states and
+    `logits(params, h, f8)` its logits. Returns (gap, ctrl_gap), ctrl_gap
+    zeros without `control`."""
     with jax.default_matmul_precision("highest"):
-        h = lm_forward(params, tokens, m, ffn, False)[at]
-        ref = mm(h, params["head"]["w"], False)
+        h = forward(params, tokens, m, False)[at]
+        ref = logits(params, h, False)
         best = ref.max(-1)
         gap = best - jnp.take_along_axis(ref, served[:, None], -1)[:, 0]
         if not control:
             return gap, jnp.zeros_like(gap)
-        hc = lm_forward(params, tokens, m, ffn, True)[at]
-        top = mm(hc, params["head"]["w"], True).argmax(-1)
+        hc = forward(params, tokens, m, True)[at]
+        top = logits(params, hc, True).argmax(-1)
         ctrl = best - jnp.take_along_axis(ref, top[:, None], -1)[:, 0]
         return gap, ctrl

@@ -1,12 +1,16 @@
-"""The trace reduction: busy time, idle gaps and the attribution of
-device executions to the decode and chunk programs, on a hand-made trace
-and on one recorded on a TPU v5e (bench/tests/data/, written by
-bench/tools/record_trace.py from sc2-3b under a mix of spread lengths:
-the programs are the cell's, whatever lengths they served)."""
+"""The trace reduction: busy time, idle gaps, each operation's scope and
+the attribution of device executions to the decode and chunk programs,
+on hand-made traces and on ones recorded on a TPU v5e (bench/tests/data/,
+written by bench/tools/record_trace.py from sc2-3b: under a mix of
+spread lengths, the programs being the cell's whatever lengths they
+served; and, with each operation's scope, as `bench/tools/
+record_trace.py sc2-3b.azure-code 1618033988749 34 OUT`)."""
 
 import gzip
 import json
 import os
+import struct
+import types
 
 import pytest
 
@@ -16,7 +20,10 @@ from metrics import common as mc
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 RECORDED = os.path.join(DATA, "sc2-3b.trace.json.gz")
+# recorded with each operation's scope
+SCOPED = os.path.join(DATA, "sc2-3b.scope.trace.json.gz")
 CELL = "sc2-3b.azure-code"
+DECODE_SCOPE = "jit(engine_decode)"
 
 
 def op(name, run, start, dur):
@@ -59,8 +66,103 @@ def test_idle_gaps_are_named_by_the_innermost_host_span():
     assert reduced["devices"]["/device:TPU:0"]["busy_s"] == 2.0
 
 
-def load_recorded():
-    with gzip.open(RECORDED, "rt") as f:
+def event(name, start_ns, dur_ns, **stats):
+    """A profiler event as `ProfileData` gives one."""
+    return types.SimpleNamespace(name=name, start_ns=start_ns,
+                                 duration_ns=dur_ns,
+                                 stats=list(stats.items()))
+
+
+def varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def field(num: int, value) -> bytes:
+    """One protobuf field: an int as a varint, a float as a double, text
+    or bytes length-delimited."""
+    if isinstance(value, int):
+        return varint(num << 3) + varint(value)
+    if isinstance(value, float):
+        return varint(num << 3 | 1) + struct.pack("<d", value)
+    value = value.encode() if isinstance(value, str) else value
+    return varint(num << 3 | 2) + varint(len(value)) + value
+
+
+def plane(name, ops, stat_names) -> bytes:
+    """An XPlane: its name, a line of events the reader passes over, the
+    stat names, and each op's event metadata with its stats
+    ({stat id: value}; a ("ref", id) value refers to a stat name)."""
+    out = field(2, name) + field(3, field(2, "XLA Ops") + field(4, b"x"))
+    for sid, sname in stat_names.items():
+        out += field(5, field(1, sid) + field(2, field(1, sid)
+                                              + field(2, sname)))
+    for mid, (op_name, stats) in enumerate(ops, 1):
+        md = field(1, mid) + field(2, op_name)
+        for sid, v in stats.items():
+            val = (field(7, v[1]) if isinstance(v, tuple)
+                   else field(5, v) if isinstance(v, str) else field(3, v))
+            md += field(5, field(1, sid) + val)
+        out += field(4, field(1, mid) + field(2, md))
+    return out
+
+
+def test_scope_is_read_where_the_metadata_has_it(tmp_path):
+    scope = "jit(engine_decode)/jit(main)/while/body/pallas_call"
+    kernel = "%tiled_matmul.3 = bf16[8,3072]{1,0} custom-call(%a, %b)"
+    fusion = "%fusion.1 = f32[8]{0} fusion(%c)"
+    copy = "%copy.2 = f32[8]{0} copy(%d)"
+    names = {1: tr.SCOPE_STAT, 2: tr.PROGRAM_STAT, 3: "flops",
+             4: "jit(engine_decode)/copy:"}
+    device = plane("/device:TPU:0", [
+        (kernel, {2: 42, 1: scope + ":", 3: 1.5}),
+        (fusion, {2: 42, 3: 2.0}),
+        (copy, {2: 42, 1: ("ref", 4)})], names)
+    host = plane("/host:CPU", [(kernel, {1: "host/op:"})], names)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(field(1, host) + field(1, device) + field(4, "h"))
+    scopes = tr.op_scopes(str(path))
+    assert scopes == {"/device:TPU:0": {
+        ("42", kernel): scope, ("42", copy): "jit(engine_decode)/copy"}}
+    lines = [
+        types.SimpleNamespace(name=tr.MODULES_LINE, events=[
+            event("jit_engine_decode(42)", 0, 10_000, run_id=7)]),
+        types.SimpleNamespace(name=tr.OPS_LINE, events=[
+            event(kernel, 1000, 2000), event(fusion, 4000, 500)])]
+    ops = tr._device_ops(types.SimpleNamespace(lines=lines),
+                         scopes["/device:TPU:0"])
+    assert [o.scope for o in ops] == [scope, ""]
+    assert [o.run for o in ops] == ["jit_engine_decode(42)#7"] * 2
+    assert ops[0].text == "%tiled_matmul.3 bf16[8,3072]{1,0}"
+    assert mc.in_scope(ops, "jit(engine_decode)") == [ops[0]]
+    # a whole component of the path, not a part of one
+    assert mc.in_scope(ops, "engine_decode") == []
+
+
+def test_scope_survives_the_saved_trace(tmp_path):
+    from tools import record_trace
+
+    ops = [tr.Op("%tiled_matmul.1", "%tiled_matmul.1 bf16[8,64]{1,0}",
+                 "jit_engine_decode(1)#1", 10.5, 0.1,
+                 "jit(engine_decode)/pallas_call"),
+           tr.Op("%copy.2", "%copy.2 bf16[8]{0}", "jit_engine_decode(1)#1",
+                 10.7, 0.1)]
+    steps = [harness.Step(10.0, 11.0, 0, 8, [])]
+    out = str(tmp_path / "t.json.gz")
+    record_trace.dump({"devices": {"/device:TPU:0": ops}, "host": []},
+                      (10.0, 11.0), steps, 10.0, out)
+    raw, span, got_steps = load_recorded(out)
+    reduced = tr.reduce(raw, span, got_steps)
+    kept = reduced["devices"]["/device:TPU:0"]["ops"]
+    assert [o.scope for o in kept] == [o.scope for o in ops]
+
+
+def load_recorded(path=RECORDED):
+    with gzip.open(path, "rt") as f:
         d = json.load(f)
     devices = {k: [tr.Op(*row) for row in v] for k, v in d["devices"].items()}
     host = [tuple(h) for h in d["host"]]
@@ -132,3 +234,25 @@ def test_recorded_kernel_calls_carry_their_weight_staging():
                 "dynamic-slice_bitcast_fusion", "pad"}
         staged_s = sum(o.dur for _, st in calls for o in st)
         assert staged_s > sum(op.dur for op, _ in calls)
+
+
+@pytest.mark.skipif(not os.path.exists(SCOPED),
+                    reason="no recorded TPU trace with scopes")
+def test_recorded_kernel_ops_carry_their_program_scope():
+    """Every tiled GEMM call of the engine's programs has a scope, and the
+    decode program's scope picks out its own calls and no chunk call."""
+    raw, span, steps = load_recorded(SCOPED)
+    reduced = tr.reduce(raw, span, steps)
+    dev = reduced["devices"][sorted(reduced["devices"])[0]]["ops"]
+    engine = [o for o in dev if o.run.startswith("jit_engine_")]
+    kernels = [o for o in engine if mc.is_kernel(o)]
+    assert kernels and all(o.scope for o in kernels)
+    runs = list(mc.program_runs({"trace": reduced}))
+    decodes = [d for _, d, _ in runs if d is not None]
+    chunks = [c for _, _, cs in runs for c in cs]
+    assert decodes and chunks
+    for dec in decodes:
+        picked = mc.in_scope(dec, DECODE_SCOPE)
+        assert [o for o in dec if mc.is_kernel(o)] == [
+            o for o in picked if mc.is_kernel(o)]
+    assert not any(mc.in_scope(c, DECODE_SCOPE) for c in chunks)

@@ -1,8 +1,8 @@
 """Run one cell like `bench/run.py --trace 1` with a short traced span and
-keep what the reduction read: the device operations and host spans of
-the span (times relative to the window's opening) and the benchmark's
-log of steps, as gzipped JSON. That file is the recorded input of the
-trace-reduction test.
+keep what the reduction read: the device operations (each with its
+scope) and host spans of the span (times relative to the window's
+opening) and the benchmark's log of steps, as gzipped JSON. That file is
+the recorded input of the trace-reduction test.
 
     python3 bench/tools/record_trace.py WORKLOAD SEED SECONDS OUT.json.gz
 """
@@ -24,7 +24,7 @@ def dump(raw: dict, span, steps, t0: float, out: str) -> None:
     relative to `t0`."""
     a, b = span
     devices = {
-        name: [[o.name, o.text[:300], o.run, o.start - t0, o.dur]
+        name: [[o.name, o.text[:300], o.run, o.start - t0, o.dur, o.scope]
                for o in ops if o.end > a and o.start < b]
         for name, ops in raw["devices"].items()}
     host = [[n, s - t0, d, ln] for n, s, d, ln in raw["host"]

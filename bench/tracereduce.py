@@ -21,19 +21,27 @@ STEP_SPAN = "bench:serve_step"
 # operations that hold others (a layer scan's loop): their time is their
 # body's, so the breakdown leaves them out
 CONTROL_FLOW = ("while", "conditional", "call")
+# the stat of a device operation's event metadata that holds its HLO
+# `op_name` path (the jit names, named scopes and primitive it was traced
+# under) as `<path>:`; the metadata's `program_id` names its program
+SCOPE_STAT = "tf_op"
+PROGRAM_STAT = "program_id"
 
 
 @dataclasses.dataclass
 class Op:
     """One device operation: its HLO instruction name, that name with the
     instruction's result type (what kernel patterns match), the program
-    execution it ran in (`<program>#<run id>`), its start and duration."""
+    execution it ran in (`<program>#<run id>`), its start and duration,
+    and its scope: the `op_name` path it was traced under ("" where the
+    trace does not say)."""
 
     name: str
     text: str
     run: str
     start: float
     dur: float
+    scope: str = ""
 
     @property
     def end(self) -> float:
@@ -65,9 +73,103 @@ def _run_id(ev) -> str:
     return ""
 
 
-def _device_ops(plane) -> list[Op]:
+def _varint(buf: bytes, i: int) -> tuple[int, int]:
+    out = shift = 0
+    while True:
+        b = buf[i]
+        i += 1
+        out |= (b & 0x7F) << shift
+        if b < 0x80:
+            return out, i
+        shift += 7
+
+
+def _fields(buf: bytes, i: int, end: int):
+    """(field number, value) of each field of the protobuf message in
+    `buf[i:end]`: a varint as its number, a length-delimited field as its
+    (start, end) in `buf`; fixed-width fields are passed over."""
+    while i < end:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            v, i = _varint(buf, i)
+        elif kind == 2:
+            n, i = _varint(buf, i)
+            v, i = (i, i + n), i + n
+        elif kind in (1, 5):
+            i += 8 if kind == 1 else 4
+            continue
+        else:
+            raise ValueError(f"protobuf wire type {kind} at byte {i}")
+        yield key >> 3, v
+
+
+def _map_values(buf: bytes, entries: list) -> dict:
+    """{key: (start, end) of the value} of protobuf map entries."""
+    out = {}
+    for a, b in entries:
+        f = dict(_fields(buf, a, b))
+        out[f.get(1, 0)] = f.get(2, (b, b))
+    return out
+
+
+def op_scopes(path: str) -> dict[str, dict[tuple[str, str], str]]:
+    """Per device plane of the XSpace file at `path`, the scope of each
+    operation it names: {(program id, operation name): op_name path},
+    from the `SCOPE_STAT` of the plane's event metadata. `ProfileData`
+    gives an event's own stats only, so this reads the metadata from the
+    file (XSpace.planes 1; XPlane name 2, event_metadata 4, stat_metadata
+    5; XEventMetadata name 2, stats 5; XStat metadata_id 1, uint64 3,
+    int64 4, str 5, ref 7; XStatMetadata name 2)."""
+    with open(path, "rb") as f:
+        buf = f.read()
+    out = {}
+    for num, (a, b) in _fields(buf, 0, len(buf)):
+        if num != 1:
+            continue
+        parts: dict[int, list] = {2: [], 4: [], 5: []}
+        for n, v in _fields(buf, a, b):
+            if n in parts:
+                parts[n].append(v)
+        name = "".join(buf[x:y].decode() for x, y in parts[2])
+        if not DEVICE_PLANE.match(name):
+            continue
+        stat_names = {}
+        for key, (x, y) in _map_values(buf, parts[5]).items():
+            f = dict(_fields(buf, x, y))
+            if 2 in f:
+                stat_names[key] = buf[f[2][0]:f[2][1]].decode()
+        scopes = {}
+        for x, y in _map_values(buf, parts[4]).values():
+            op, got = "", {}
+            for n, v in _fields(buf, x, y):
+                if n == 2:
+                    op = buf[v[0]:v[1]].decode()
+                elif n == 5:
+                    st = dict(_fields(buf, *v))
+                    which = stat_names.get(st.get(1))
+                    if 5 in st:
+                        got[which] = buf[st[5][0]:st[5][1]].decode()
+                    elif 7 in st:
+                        got[which] = stat_names.get(st[7], "")
+                    else:
+                        got[which] = str(st.get(3, st.get(4, "")))
+            if got.get(SCOPE_STAT):
+                scopes[(got.get(PROGRAM_STAT, ""), op)] = (
+                    got[SCOPE_STAT].rpartition(":")[0] or got[SCOPE_STAT])
+        out[name] = scopes
+    return out
+
+
+def _program_id(run: str) -> str:
+    """The program id in an execution's name, `<program>(<id>)#<run>`."""
+    return run.rpartition("#")[0].rpartition("(")[2].rstrip(")")
+
+
+def _device_ops(plane, scopes: dict) -> list[Op]:
     """The plane's operations, each given the program execution (an
-    event of the modules line) that holds it."""
+    event of the modules line) that holds it and its scope from
+    `scopes` ({(program id, operation name): path}, `op_scopes`)."""
     lines = {ln.name: ln for ln in plane.lines}
     mods = sorted(((ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9,
                     f"{ev.name}#{_run_id(ev)}")
@@ -83,7 +185,8 @@ def _device_ops(plane) -> list[Op]:
             run = mods[j][2] if j < len(mods) and mods[j][0] <= t else ""
             head, _, rest = ev.name.partition(" = ")
             ops.append(Op(head, f"{head} {rest.split(' ', 1)[0]}", run, t,
-                          ev.duration_ns * 1e-9))
+                          ev.duration_ns * 1e-9,
+                          scopes.get((_program_id(run), ev.name), "")))
     return sorted(ops, key=lambda o: o.start)
 
 
@@ -93,11 +196,12 @@ def load(path: str) -> dict:
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
+    scopes = op_scopes(path)
     devices: dict[str, list[Op]] = {}
     host: list[tuple[str, float, float, str]] = []
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):
-            ops = _device_ops(plane)
+            ops = _device_ops(plane, scopes.get(plane.name, {}))
             if ops:
                 devices[plane.name] = ops
         elif plane.name.startswith("/host:"):
